@@ -43,6 +43,7 @@ import json
 import sys
 import time
 import warnings
+from pathlib import Path
 
 SUBCOMMANDS = ("run", "sweep", "guidelines", "audit", "compare", "calibrate")
 
@@ -620,6 +621,9 @@ def main(argv: list[str] | None = None) -> None:
     p_cmp.add_argument("store_b", metavar="STOREB")
 
     args = ap.parse_args(argv)
+    from repro.core.runtime_meter import use_compile_cache
+
+    use_compile_cache(str(Path(__file__).resolve().parents[1]))
     if getattr(args, "seed", 0) < 0:
         ap.error("--seed must be >= 0 (it offsets non-negative RNG seeds)")
     if args.cmd == "sweep" and args.faults and args.fleet is None:

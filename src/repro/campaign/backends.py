@@ -13,7 +13,8 @@ against
     ``all_gather`` / ``all_to_all``) over a host-device mesh
     (``--xla_force_host_platform_device_count`` off-TPU),
   * :class:`KernelBackend` — Pallas kernels vs. their jnp references as
-    the operations under test (interpret mode off-TPU).
+    the operations under test (compiled on a TPU, interpret mode
+    elsewhere).
 
 Backends are plain picklable dataclasses so
 :func:`~repro.core.design.run_design` can fan their launch epochs over a
@@ -383,9 +384,11 @@ class SimBackend:
                 pos[e] += 1
 
     def factors(self, design: ExperimentDesign) -> FactorSet:
+        # The jit engine runs on JAX's default device, which the factor set
+        # then names; the numpy engines never touch a device.
+        device = {} if self.engine == "jax" else dict(backend="sim",
+                                                      device_kind="simnet")
         return capture_factors(
-            backend="sim",
-            device_kind="simnet",
             measurement_backend=self.name,
             sync_method=self.sync_name,
             window_size_us=self.win_size * 1e6,
@@ -400,6 +403,7 @@ class SimBackend:
                    ("sync_kw", tuple(sorted(self.sync_kw.items()))),
                    ("clock_kw", tuple(sorted(self.clock_kw.items()))),
                    ("engine", self.engine)),
+            **device,
             **_design_factor_kw(design),
         )
 
@@ -457,18 +461,37 @@ class JaxBackend:
                 "available — set --xla_force_host_platform_device_count")
         return n
 
+    def _input(self, op: str, msize: int, n: int) -> np.ndarray:
+        """The host-side input of one collective: ``n`` per-device payloads
+        of ``msize`` bytes (padded so all_to_all's split axis divides),
+        small integers whose sums stay exact even in bfloat16, laid out
+        differently on each device so a misrouted block changes the
+        output."""
+        import jax.numpy as jnp
+
+        dtype = jnp.dtype(self.dtype)
+        count = max(n, int(np.ceil(msize / dtype.itemsize)))
+        count = int(np.ceil(count / n)) * n
+        shape = (n, n, count // n) if op == "all_to_all" else (n, count)
+        vals = (7 * np.arange(n)[:, None] + np.arange(count)[None, :]) % 16
+        return vals.reshape(shape).astype(dtype)
+
+    def _place_input(self, op: str, msize: int, n: int):
+        """The input placed once, one payload per device, so the timed call
+        moves no data before its collective starts."""
+        import jax
+        from jax.sharding import PmapSharding
+
+        host = self._input(op, msize, n)
+        return jax.device_put(host, PmapSharding.default(
+            host.shape, 0, jax.devices()[:n]))
+
     def _build_collective(self, op: str, msize: int, n: int | None = None):
         import jax
-        import jax.numpy as jnp
         from jax import lax
 
         n = self._ndev() if n is None else n
-        itemsize = jnp.dtype(self.dtype).itemsize
-        # per-device payload, padded so all_to_all's split axis divides
-        count = max(n, int(np.ceil(msize / itemsize)))
-        count = int(np.ceil(count / n)) * n
         devices = jax.devices()[:n]
-        shape = (n, count)
         if op == "psum":
             f = jax.pmap(lambda x: lax.psum(x, "i"), axis_name="i",
                          devices=devices)
@@ -477,15 +500,27 @@ class JaxBackend:
                          devices=devices)
         elif op == "all_to_all":
             # split axis must equal the mesh size: (n, count//n) per device
-            shape = (n, n, count // n)
             f = jax.pmap(lambda x: lax.all_to_all(x, "i", 0, 0),
                          axis_name="i", devices=devices)
         else:
             raise ValueError(f"JaxBackend: unknown collective {op!r}; "
                              f"one of {self.ops}")
-        x = jnp.zeros(shape, self.dtype) + jnp.arange(n).reshape(
-            (n,) + (1,) * (len(shape) - 1))
+        x = self._place_input(op, msize, n)
         return lambda: f(x)
+
+    def check(self, op: str, msize: int) -> float:
+        """Run one collective on all ``n`` devices and return the largest
+        absolute difference from its numpy result (0.0 when exact)."""
+        n = self._ndev()
+        host = self._input(op, msize, n)
+        if op == "psum":
+            want = np.broadcast_to(host.sum(axis=0), host.shape)
+        elif op == "all_gather":
+            want = np.broadcast_to(host[None], (n,) + host.shape)
+        else:
+            want = np.swapaxes(host, 0, 1)
+        got = np.asarray(self._build_collective(op, msize, n)())
+        return float(np.max(np.abs(got.astype(np.float64) - want)))
 
     def _build_case(self, opexpr: str, msize: int):
         """Build the timed callable for a case — a single collective, or a
@@ -565,7 +600,6 @@ class KernelBackend:
     kv_heads: int | None = None
     head_dim: int = 32
     state_dim: int = 16
-    interpret: bool | None = None     # None = auto (interpret off-TPU)
     seed0: int = 0
     meter: MeterConfig = field(
         default_factory=lambda: MeterConfig(epoch_isolation="clear_caches",
@@ -590,7 +624,7 @@ class KernelBackend:
                 t.op, t.impl or self.impl, seq=t.msize(msize),
                 batch=self.batch, heads=self.heads, kv_heads=self.kv_heads,
                 head_dim=self.head_dim, state_dim=self.state_dim,
-                seed=self.seed0 + epoch, interpret=self.interpret))
+                seed=self.seed0 + epoch))
         return _sequence_calls(fns)
 
     def measure(self, ctx: JaxEpochContext, case: TestCase,
@@ -609,8 +643,7 @@ class KernelBackend:
             extra=(("impl", self.impl), ("batch", self.batch),
                    ("heads", self.heads), ("kv_heads", self.kv_heads),
                    ("head_dim", self.head_dim),
-                   ("state_dim", self.state_dim), ("seed0", self.seed0),
-                   ("interpret", self.interpret)),
+                   ("state_dim", self.state_dim), ("seed0", self.seed0)),
             **_design_factor_kw(design),
         )
 

@@ -30,6 +30,7 @@ the simulation backends do).
 from __future__ import annotations
 
 import dataclasses
+import sys
 import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
@@ -49,6 +50,8 @@ __all__ = [
     "measure_adaptive",
     "run_design",
     "map_parallel",
+    "holds_tpu",
+    "refuse_workers_on_tpu",
     "analyze_records",
     "NREP_SPENT",
 ]
@@ -374,6 +377,33 @@ def run_design(
     return records
 
 
+def holds_tpu() -> bool:
+    """Whether this process has claimed a TPU. JAX claims the chip when
+    its backends start (the first device query or array); a process that
+    has not imported JAX, or not started its backends, holds nothing."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def refuse_workers_on_tpu(what: str) -> None:
+    """Raise instead of starting worker processes from a process that
+    holds a TPU: the chip belongs to one process at a time, so a worker
+    that needs it fails or hangs."""
+    if holds_tpu():
+        raise RuntimeError(
+            f"{what}: this process holds a TPU, which belongs to one process "
+            "at a time; worker processes started from it cannot use the "
+            "chip and would fail or hang. Run with one worker, so that "
+            "every measurement happens in this process.")
+
+
 def map_parallel(
     fn: Callable,
     argtuples: list[tuple],
@@ -418,6 +448,7 @@ def map_parallel(
 
     if not argtuples:
         return []
+    refuse_workers_on_tpu(f"map_parallel({what}, n_workers={n_workers})")
     try:
         pickle.dumps((fn, argtuples))
     except Exception:

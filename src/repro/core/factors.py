@@ -86,23 +86,25 @@ def capture_factors(**overrides) -> FactorSet:
     values, but never *silently*: the failure reason is recorded in
     ``extra`` so a degraded capture shows up in fingerprint diffs instead
     of masquerading as a comparable environment.
+
+    The device is queried only when ``backend`` and ``device_kind`` are
+    not both given: asking JAX for its devices claims the accelerator for
+    this process, which a backend that never touches it (the numpy
+    simulator) must not do.
     """
     failure: tuple = ()
+    base = dict(backend="unknown", device_kind="unknown",
+                jax_version="unknown",
+                xla_flags=os.environ.get("XLA_FLAGS", ""))
     try:
         import jax
 
-        backend = jax.default_backend()
-        device_kind = jax.devices()[0].device_kind
-        jax_version = jax.__version__
+        base["jax_version"] = jax.__version__
+        if not {"backend", "device_kind"} <= overrides.keys():
+            base["backend"] = jax.default_backend()
+            base["device_kind"] = jax.devices()[0].device_kind
     except Exception as e:
-        backend, device_kind, jax_version = "unknown", "unknown", "unknown"
         failure = (("capture_failure", f"{type(e).__name__}: {e}"),)
-    base = dict(
-        backend=backend,
-        device_kind=device_kind,
-        jax_version=jax_version,
-        xla_flags=os.environ.get("XLA_FLAGS", ""),
-    )
     base.update(overrides)
     if failure:
         base["extra"] = tuple(base.get("extra", ())) + failure

@@ -22,13 +22,36 @@ applies:
 from __future__ import annotations
 
 import gc
+import os
 import time
 from dataclasses import dataclass
 from typing import Any, Callable
 
 import numpy as np
 
-__all__ = ["timed_calls", "JaxEpochContext", "make_jax_measure", "MeterConfig"]
+__all__ = ["timed_calls", "JaxEpochContext", "make_jax_measure", "MeterConfig",
+           "use_compile_cache"]
+
+
+def use_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point that
+    measures on a device, and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is configured here. Otherwise the cache lives at the fixed path
+    ``<root>/.jax_cache``: a directory that moved between runs would never
+    hit. Launch epochs clear the in-memory jit cache
+    (:class:`JaxEpochContext`), so each epoch's recompile of a large
+    program is read back from this cache.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def timed_calls(fn: Callable[[], Any], nrep: int, warmup: int = 3) -> np.ndarray:

@@ -39,7 +39,7 @@ from repro.campaign.core import Campaign, CampaignSpec
 from repro.campaign.store import ResultStore
 from repro.campaign.sweep import (CellResult, SweepResult, SweepScheduler,
                                   SweepSpec)
-from repro.core.design import analyze_records
+from repro.core.design import analyze_records, refuse_workers_on_tpu
 from repro.core.retry import RetryPolicy
 
 from .faults import CRASH_EXIT_CODE, FaultPlan, FaultyBackend
@@ -254,6 +254,8 @@ class FleetScheduler(SweepScheduler):
     # -- multi-process mode --------------------------------------------------
 
     def _drive_fleet(self, queue, pending, sweep_id, snapshot):
+        refuse_workers_on_tpu(
+            f"FleetScheduler(n_workers={self.config.n_workers})")
         cfg = self.config
         shard_dir = (Path(cfg.shard_dir) if cfg.shard_dir else
                      self.store.path.parent /

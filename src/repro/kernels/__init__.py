@@ -4,12 +4,11 @@
     score traffic that makes the reference path memory-bound (§Roofline).
   * ``ssd_scan``        — Mamba-2 chunked SSD with VMEM-resident
     inter-chunk state.
-  * ``sim_scan``        — fused AR(1) scan + bimodal-tail/spike mixture
-    for the simulator's duration sampling (``repro.simjax``); the carry
-    rides VMEM scratch across sequential chunks.
 
 Kernels target TPU (``pl.pallas_call`` + BlockSpec VMEM tiling) and are
-validated on CPU in interpret mode against ``<kernel>/ref.py``.
+validated on CPU in interpret mode against ``<kernel>/ref.py``;
+``tests/test_v5e_compile.py`` compiles them for a described v5e.
+``sim_scan/ref.py`` is the simulator's duration sampler, plain jnp only.
 """
 
 from . import ops

@@ -22,8 +22,8 @@ Design (TPU-native, not a CUDA port):
     a scanned layer stack.
 
 Validated against ``ref.flash_attention_ref`` in interpret mode on CPU
-(tests/test_kernels/test_flash_attention.py) across shapes, dtypes, GQA
-ratios, windows and soft-caps.
+(tests/test_kernels.py) across shapes, dtypes, GQA ratios, windows and
+soft-caps; compiled for a described v5e in tests/test_v5e_compile.py.
 """
 
 from __future__ import annotations
@@ -34,20 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific niceties are optional in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-
-    def _compiler_params(dims):
-        try:
-            return pltpu.CompilerParams(dimension_semantics=dims)
-        except Exception:  # older name
-            return pltpu.TPUCompilerParams(dimension_semantics=dims)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -112,7 +99,7 @@ def _kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      "block_q", "block_k", "interpret", "use_window"))
 def flash_attention_fwd(q, k, v, window=None, *, causal=True, logit_cap=0.0,
                         q_offset=0, kv_len=None, block_q=512, block_k=512,
-                        interpret=True, use_window=False):
+                        interpret=False, use_window=False):
     """q: (B, H, S, D); k/v: (B, Hkv, T, D); window: () int32 or None.
 
     Returns (B, H, S, D). Static shape requirements: S % block_q == 0,
@@ -135,17 +122,6 @@ def flash_attention_fwd(q, k, v, window=None, *, causal=True, logit_cap=0.0,
         q_offset=q_offset, kv_len=kv_len, bq=bq, bk=bk, nk=nk,
         use_window=use_window)
 
-    kwargs = {}
-    if _VMEM is not None:
-        kwargs["scratch_shapes"] = [
-            _VMEM((bq, 1), jnp.float32),
-            _VMEM((bq, 1), jnp.float32),
-            _VMEM((bq, d), jnp.float32),
-        ]
-        if not interpret:
-            kwargs["compiler_params"] = _compiler_params(
-                ("parallel", "parallel", "parallel", "arbitrary"))
-
     return pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -157,6 +133,13 @@ def flash_attention_fwd(q, k, v, window=None, *, causal=True, logit_cap=0.0,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(window, q, k, v)

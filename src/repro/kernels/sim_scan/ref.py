@@ -1,4 +1,8 @@
-"""Pure-jnp oracle for the fused duration-sampling scan.
+"""The simulator's duration sampler (``repro.simjax``), in plain jnp.
+
+There is no Pallas version: the simulator is float64 end to end, and the
+TPU's kernel compiler (Mosaic) accepts no f64 operand, nor any kernel
+traced inside an x64 scope. XLA compiles this one for the chip.
 
 The exact math of :meth:`repro.core.mpi_ops.SimCollective.sample_durations`
 on pre-drawn noise: the AR(1) recurrence ``s_i = coeff * s_{i-1} + eps_i``

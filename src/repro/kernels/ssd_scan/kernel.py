@@ -7,16 +7,21 @@ TPU adaptation of the SSD algorithm [arXiv:2405.21060]:
     VMEM scratch carried across chunk iterations (the GPU version
     materializes per-chunk states in HBM and runs a separate scan kernel;
     on TPU the sequential grid + persistent scratch fuses both passes).
-  * Within a chunk everything is dense matmul work for the MXU:
-    ``G = C B^T`` (l x l), the decay-masked intra-chunk product, and the
-    state outer products — block sizes chosen so the f32 ``(l, l)``
-    decay/score tile fits VMEM alongside x/B/C blocks
-    (l=256, hg=8, p=64, n=64..128 → ~1.5 MiB working set).
-  * Heads are grouped (``head_group``) to bound the ``(l, l, hg)`` masked
-    tile; B/C are shared across heads (single SSD group, as in mamba2).
+  * Heads lead the sequence axis (``x`` is laid out ``(b, h, s, p)`` and
+    ``dt*A`` as ``(b, h, s)``), so every block's last two dimensions are
+    ``(hg, chunk)``, ``(chunk, p)`` or ``(chunk, n)`` and obey the TPU's
+    (8, 128) tiling at model widths (hg=8, chunk=256).
+  * Within a chunk everything is 2-D matmul work for the MXU, one head at
+    a time: ``G = C B^T`` (l x l, shared by the group's heads), the
+    decay-masked intra-chunk product, and the state outer products. The
+    in-chunk cumulative sum of ``dt*A`` is a matmul with a triangular
+    ones matrix — the TPU lowering has no ``cumsum``.
+  * B/C are shared across heads (single SSD group, as in mamba2), so their
+    blocks are indexed by chunk only and never copied per head group.
 
 Validated against ``ref.ssd_chunked_ref`` in interpret mode
-(tests/test_kernels/test_ssd_scan.py).
+(tests/test_kernels.py); compiled for a described v5e at mamba2-1.3b widths
+in tests/test_v5e_compile.py.
 """
 
 from __future__ import annotations
@@ -25,110 +30,85 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-
-    def _compiler_params(dims):
-        try:
-            return pltpu.CompilerParams(dimension_semantics=dims)
-        except Exception:
-            return pltpu.TPUCompilerParams(dimension_semantics=dims)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssd_scan_fwd"]
 
+_HI = lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))     # contract the last dim of both operands
+_TN = (((0,), (0,)), ((), ()))     # contract the first dim of both operands
 
-def _kernel(x_ref, dta_ref, b_ref, c_ref, y_ref, state_scr, *, l, hg, p, n, nc):
-    ic = pl.program_id(1)
 
-    @pl.when(ic == 0)
+def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *, l, hg):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, 0]                                  # (l, hg, p)
-    dta = dta_ref[0, 0].astype(jnp.float32)          # (l, hg)
-    B = b_ref[0, 0]                                  # (l, n)
-    C = c_ref[0, 0]                                  # (l, n)
+    a = a_ref[0].astype(jnp.float32)                 # (hg, l)
+    B = b_ref[0]                                     # (l, n)
+    C = c_ref[0]                                     # (l, n)
+    row = lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    col = lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    tri = row >= col                                 # t >= s
+    # inclusive cumsum over the chunk, in both orientations
+    cs = jnp.dot(a, (row <= col).astype(jnp.float32), precision=_HI,
+                 preferred_element_type=jnp.float32)           # (hg, l)
+    cs_t = lax.dot_general(tri.astype(jnp.float32), a, _NT, precision=_HI,
+                           preferred_element_type=jnp.float32)  # (l, hg)
+    g = lax.dot_general(C, B, _NT, preferred_element_type=jnp.float32)
+    C32 = C.astype(jnp.float32)
 
-    cs = jnp.cumsum(dta, axis=0)                     # (l, hg)
-    last = cs[-1:, :]                                # (1, hg)
-
-    # ---- intra-chunk ----------------------------------------------------
-    dec = cs[:, None, :] - cs[None, :, :]            # (l, l, hg)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    dec = jnp.where(tri[:, :, None], dec, -jnp.inf)
-    dec = jnp.exp(dec)
-    g = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (l, l)
-    m = (g[:, :, None] * dec).astype(x.dtype)        # (l, l, hg)
-    y_intra = jnp.einsum("tsh,shp->thp", m, x)
-
-    # ---- inter-chunk ----------------------------------------------------
-    state = state_scr[...]                           # (hg, p, n) f32
-    y_inter = jnp.einsum("tn,hpn,th->thp", C.astype(jnp.float32), state,
-                         jnp.exp(cs)).astype(x.dtype)
-
-    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
-
-    # ---- state update ----------------------------------------------------
-    w = jnp.exp(last - cs).astype(x.dtype)           # (l, hg)
-    new_contrib = jnp.einsum("th,tn,thp->hpn", w, B, x).astype(jnp.float32)
-    chunk_decay = jnp.exp(last[0]).astype(jnp.float32)  # (hg,)
-    state_scr[...] = state * chunk_decay[:, None, None] + new_contrib
+    for j in range(hg):
+        x = x_ref[0, j]                              # (l, p)
+        ct = cs_t[:, j:j + 1]                        # (l, 1)
+        # the chunk total (a lane reduction: Mosaic cannot broadcast a
+        # single element sliced from lane l-1 across a whole tile)
+        last = jnp.sum(a[j:j + 1, :], axis=1, keepdims=True)   # (1, 1)
+        # intra-chunk: decay(t, s) = exp(cs_t - cs_s) for s <= t
+        dec = jnp.exp(jnp.where(tri, ct - cs[j:j + 1, :], -jnp.inf))
+        m = (g * dec).astype(x.dtype)
+        y = jnp.dot(m, x, preferred_element_type=jnp.float32)
+        # inter-chunk: the state carried in from earlier chunks
+        st = state_scr[j]                            # (p, n) f32
+        y = y + lax.dot_general(C32, st, _NT,
+                                preferred_element_type=jnp.float32) \
+            * jnp.exp(ct)
+        y_ref[0, j] = y.astype(y_ref.dtype)
+        # state update
+        xw = x * jnp.exp(last - ct).astype(x.dtype)  # (l, p)
+        contrib = lax.dot_general(xw, B, _TN,
+                                  preferred_element_type=jnp.float32)
+        state_scr[j] = st * jnp.exp(last) + contrib
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "head_group", "interpret"))
-def ssd_scan_fwd(x, dta, B, C, *, chunk=256, head_group=8, interpret=True):
+def ssd_scan_fwd(x, dta, B, C, *, chunk=256, head_group=8, interpret=False):
     """x: (b, s, h, p); dta: (b, s, h); B/C: (b, s, n). Returns y like x.
 
-    Requirements: s % chunk == 0, h % head_group == 0 (``ops.py`` pads).
+    Requirements: s % chunk == 0, h % head_group == 0 (``ops.py`` checks).
     """
     b, s, h, p = x.shape
     n = B.shape[-1]
     l = min(chunk, s)
-    nc = s // l
     hg = min(head_group, h)
-    ng = h // hg
-
-    xr = x.reshape(b, nc, l, ng, hg, p).transpose(0, 3, 1, 2, 4, 5) \
-        .reshape(b * ng, nc, l, hg, p)
-    dr = dta.reshape(b, nc, l, ng, hg).transpose(0, 3, 1, 2, 4) \
-        .reshape(b * ng, nc, l, hg)
-    Br = jnp.broadcast_to(B.reshape(b, 1, nc, l, n), (b, ng, nc, l, n)) \
-        .reshape(b * ng, nc, l, n)
-    Cr = jnp.broadcast_to(C.reshape(b, 1, nc, l, n), (b, ng, nc, l, n)) \
-        .reshape(b * ng, nc, l, n)
-
-    kernel = functools.partial(_kernel, l=l, hg=hg, p=p, n=n, nc=nc)
-    kwargs = {}
-    if _VMEM is not None:
-        kwargs["scratch_shapes"] = [_VMEM((hg, p, n), jnp.float32)]
-        if not interpret:
-            kwargs["compiler_params"] = _compiler_params(
-                ("parallel", "arbitrary"))
 
     y = pl.pallas_call(
-        kernel,
-        grid=(b * ng, nc),
+        functools.partial(_kernel, l=l, hg=hg),
+        grid=(b, h // hg, s // l),
         in_specs=[
-            pl.BlockSpec((1, 1, l, hg, p), lambda ib, ic: (ib, ic, 0, 0, 0)),
-            pl.BlockSpec((1, 1, l, hg), lambda ib, ic: (ib, ic, 0, 0)),
-            pl.BlockSpec((1, 1, l, n), lambda ib, ic: (ib, ic, 0, 0)),
-            pl.BlockSpec((1, 1, l, n), lambda ib, ic: (ib, ic, 0, 0)),
+            pl.BlockSpec((1, hg, l, p), lambda ib, ig, ic: (ib, ig, ic, 0)),
+            pl.BlockSpec((1, hg, l), lambda ib, ig, ic: (ib, ig, ic)),
+            pl.BlockSpec((1, l, n), lambda ib, ig, ic: (ib, ic, 0)),
+            pl.BlockSpec((1, l, n), lambda ib, ig, ic: (ib, ic, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, l, hg, p), lambda ib, ic: (ib, ic, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * ng, nc, l, hg, p), x.dtype),
+        out_specs=pl.BlockSpec((1, hg, l, p),
+                               lambda ib, ig, ic: (ib, ig, ic, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
+        scratch_shapes=[pltpu.VMEM((hg, p, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
-    )(xr, dr, Br, Cr)
-
-    y = y.reshape(b, ng, nc, l, hg, p).transpose(0, 2, 3, 1, 4, 5) \
-        .reshape(b, s, h, p)
-    return y
+    )(jnp.transpose(x, (0, 2, 1, 3)), jnp.transpose(dta, (0, 2, 1)), B, C)
+    return jnp.transpose(y, (0, 2, 1, 3))

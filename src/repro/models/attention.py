@@ -2,9 +2,10 @@
 RoPE, MLA (DeepSeek-V2 latent attention), and KV-cache decode paths.
 
 The inner attention product routes through :func:`attention_op`, which
-dispatches to the Pallas flash-attention kernel on TPU and to the pure-jnp
-reference elsewhere (the dry-run lowers the jnp path; kernels are validated
-separately in ``tests/test_kernels``).
+dispatches to the Pallas flash-attention kernel on TPU — a kernel that
+fails there raises — and to the pure-jnp reference elsewhere (the dry-run
+lowers the jnp path; kernels are validated separately in
+``tests/test_kernels.py``).
 """
 
 from __future__ import annotations
@@ -89,19 +90,13 @@ def attention_op(
     scalar (per-layer local/global selection under scan). ``q_offset`` is
     the absolute position of q[0] (decode). ``kv_len`` masks a padded cache.
     """
-    if impl == "auto":
-        try:  # prefer the Pallas kernel on TPU backends
-            import jax.extend  # noqa: F401 -- probe kernel-capable jax
+    if impl == "auto" and jax.default_backend() == "tpu":
+        from repro.kernels import ops as kops
 
-            if jax.default_backend() == "tpu":
-                from repro.kernels import ops as kops
-
-                return kops.flash_attention(
-                    q, k, v, causal=causal, window=window,
-                    logit_cap=logit_cap, q_offset=q_offset, kv_len=kv_len,
-                )
-        except Exception:
-            pass
+        return kops.flash_attention(
+            q, k, v, causal=causal, window=window,
+            logit_cap=logit_cap, q_offset=q_offset, kv_len=kv_len,
+        )
     return attention_reference(
         q, k, v, causal=causal, window=window, logit_cap=logit_cap,
         q_offset=q_offset, kv_len=kv_len,

@@ -9,7 +9,8 @@ clock conversion — over the full ``(nrep, p)`` array at once. It is
 exposed as ``run_windowed(..., engine="jax")`` and
 ``SimBackend(engine="jax")`` with zero call-site changes.
 
-The port is float64 end to end (via ``jax.experimental.enable_x64``), so
+The port is float64 end to end (inside ``jax.enable_x64(True)``, through
+the engine's one :func:`~repro.simjax.engine.x64` scope), so
 its absolute-time arithmetic carries the same resolution as the numpy
 engine; draws use JAX's counter-based PRNG, so — like PR 1's batching —
 campaigns are statistically, not bit-wise, identical to the numpy engines

@@ -7,8 +7,9 @@ One measurement call lowers to two jitted programs:
     ``lax.associative_scan`` over affine maps ``(a, b)`` (composition
     ``(a1, b1) ∘ (a2, b2) = (a1 a2, b1 a2 + b2)`` is associative, so the
     scan is exact, not an approximation), and apply the
-    lognormal/bimodal-tail/spike mixture — the jnp reference of the
-    optional fused Pallas kernel in :mod:`repro.kernels.sim_scan`;
+    lognormal/bimodal-tail/spike mixture
+    (:mod:`repro.kernels.sim_scan.ref`, plain jnp: the TPU's kernel
+    compiler takes no f64 operand);
   * ``window`` — deadline conversion, the cross-call entry recurrence
     ``all_in_i = C_i + max(max_r t0_r, cummax_i(dmax - C))``, per-rank
     finish imbalance, START_LATE / TOOK_TOO_LONG flags and global-time
@@ -42,7 +43,6 @@ and dispatches, so "one trace per campaign" is a measured quantity.
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 
 import numpy as np
@@ -74,8 +74,13 @@ def have_jax() -> bool:
         return False
 
 
-def _use_pallas_default() -> bool:
-    return os.environ.get("REPRO_SIMJAX_PALLAS", "") not in ("", "0")
+def x64():
+    """The engine's float64 scope (a context manager): every jitted program
+    of this module is traced and called inside it, because the simulator
+    is f64 end to end."""
+    import jax
+
+    return jax.enable_x64(True)
 
 
 def _bucket(nrep: int) -> int:
@@ -132,6 +137,24 @@ def _chunk_for(p: int, n: int) -> int:
     return min(ch, n)
 
 
+def _cumsum(x):
+    """Inclusive prefix sum of a 1-D array as an associative scan. XLA:TPU
+    lowers ``jnp.cumsum`` to a reduce-window, whose f64 emulation takes
+    minutes to compile (207 s for 2048 doubles on a described v5e)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.associative_scan(jnp.add, x)
+
+
+def _cummax(x):
+    """Inclusive running max, as an associative scan for the same reason."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.associative_scan(jnp.maximum, x)
+
+
 @functools.lru_cache(maxsize=1)
 def _cores():
     """The raw (un-jitted) sample/window math, built once. Shared by the
@@ -143,20 +166,17 @@ def _cores():
                                 "importable in this environment")
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     def sample(key, t0_op, ar_state, noise_sigma, autocorr, tail_prob,
-               tail_shift, spike_prob, spike_scale, *, n, use_pallas):
+               tail_shift, spike_prob, spike_scale, *, n):
         k_eps, k_tail, k_mag, k_spike = jax.random.split(key, 4)
         eps = noise_sigma * jax.random.normal(k_eps, (n,), jnp.float64)
         u_tail = jax.random.uniform(k_tail, (n,), jnp.float64)
         u_mag = jax.random.uniform(k_mag, (n,), jnp.float64)
         u_spike = jax.random.uniform(k_spike, (n,), jnp.float64)
-        if use_pallas:
-            from repro.kernels.sim_scan.kernel import sim_durations_scan as fn
-        else:
-            from repro.kernels.sim_scan.ref import sim_durations_ref as fn
-        return fn(eps, u_tail, u_mag, u_spike, coeff=autocorr,
+        from repro.kernels.sim_scan.ref import sim_durations_ref
+
+        return sim_durations_ref(eps, u_tail, u_mag, u_spike, coeff=autocorr,
                   state=ar_state, t0=t0_op, tail_prob=tail_prob,
                   tail_shift=tail_shift, spike_prob=spike_prob,
                   spike_scale=spike_scale)
@@ -178,8 +198,8 @@ def _cores():
         span = durations[:, None] * jnp.maximum(0.25, 1.0 + imb)
         e = span.max(axis=1)
         dmax = deadline_true.max(axis=1)
-        C = jnp.concatenate([jnp.zeros((1,), e.dtype), jnp.cumsum(e[:-1])])
-        all_in = C + jnp.maximum(jnp.max(t0), lax.cummax(dmax - C))
+        C = jnp.concatenate([jnp.zeros((1,), e.dtype), _cumsum(e[:-1])])
+        all_in = C + jnp.maximum(jnp.max(t0), _cummax(dmax - C))
         end = all_in[:, None] + span
         prev_end = jnp.concatenate([t0[None, :], end[:-1]], axis=0)
         start = jnp.maximum(deadline_true, prev_end)
@@ -207,7 +227,7 @@ def _jitted():
     """Build (once) the jitted per-epoch sample/window cores."""
     jax, sample, window = _cores()
     return (jax,
-            jax.jit(sample, static_argnames=("n", "use_pallas")),
+            jax.jit(sample, static_argnames=("n",)),
             jax.jit(window))
 
 
@@ -244,12 +264,11 @@ def _jitted_fused():
 
     def sample_epochs(seeds, j, t0_op, ar_state, noise_sigma, autocorr,
                       tail_prob, tail_shift, spike_prob, spike_scale, nrep,
-                      *, n, use_pallas):
+                      *, n):
         def one(seed, t0e, are):
             key = jax.random.fold_in(jax.random.PRNGKey(seed), j)
             dur, s = sample(key, t0e, are, noise_sigma, autocorr, tail_prob,
-                            tail_shift, spike_prob, spike_scale, n=n,
-                            use_pallas=use_pallas)
+                            tail_shift, spike_prob, spike_scale, n=n)
             return dur, s[nrep - 1]
         return jax.vmap(one)(seeds, t0_op, ar_state)
 
@@ -297,8 +316,8 @@ def _jitted_fused():
             dmaxrel = drel.max(axis=1).astype(jnp.float64)
             T = T0 + tau
             C = Crun + jnp.concatenate(
-                [jnp.zeros((1,), jnp.float64), jnp.cumsum(e[:-1])])
-            cm = lax.cummax(jnp.concatenate([cmax[None],
+                [jnp.zeros((1,), jnp.float64), _cumsum(e[:-1])])
+            cm = _cummax(jnp.concatenate([cmax[None],
                                              T + dmaxrel - C]))[1:]
             all_in = C + jnp.maximum(maxt0, cm)
             A32 = (all_in - T).astype(jnp.float32)[:, None]
@@ -334,7 +353,7 @@ def _jitted_fused():
         return times.reshape(-1), errors.reshape(-1), et_last
 
     return (jax,
-            jax.jit(sample_epochs, static_argnames=("n", "use_pallas")),
+            jax.jit(sample_epochs, static_argnames=("n",)),
             jax.jit(window_fused, static_argnames=("ch",)))
 
 
@@ -385,7 +404,7 @@ def _terms(op, p: int, msize: int):
 
 
 def run_windowed_jax(net, sync, op, msize, nrep, win_size,
-                     ranks=None, use_pallas: bool | None = None) -> WindowRun:
+                     ranks=None) -> WindowRun:
     """JAX port of ``run_windowed``'s batch engine (affine clocks only).
 
     Strict by design: raises :class:`SimJaxUnavailable` on random-walk
@@ -399,8 +418,6 @@ def run_windowed_jax(net, sync, op, msize, nrep, win_size,
             "engine='jax' requires affine clocks (rw_sigma == 0); use "
             "engine='batch_rw' (or 'auto') for random-walk clocks")
     jax, sample, window = _jitted()
-    if use_pallas is None:
-        use_pallas = _use_pallas_default()
     if nrep <= 0:
         empty = np.empty((0, p))
         return WindowRun(times=np.empty(0),
@@ -422,17 +439,16 @@ def run_windowed_jax(net, sync, op, msize, nrep, win_size,
     intercept = np.array([sync.models[r].intercept for r in ranks])
     init_t = np.array([sync.initial_times[r] for r in ranks])
 
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with x64():
         key = jax.random.PRNGKey(seed)
         durations = None
         for j, (sub, tp, tm) in enumerate(terms):
             t0_op = sub.base_time(tp, tm) * sub._bias_for(net)
-            _STATS.count(("sample", n, use_pallas))
+            _STATS.count(("sample", n))
             dur, s = sample(jax.random.fold_in(key, j), t0_op,
                             sub._ar_state, sub.noise_sigma, sub.autocorr,
                             sub.tail_prob, sub.tail_shift, sub.spike_prob,
-                            sub.spike_scale, n=n, use_pallas=use_pallas)
+                            sub.spike_scale, n=n)
             sub._ar_state = float(s[nrep - 1])
             durations = dur if durations is None else durations + dur
         _STATS.count(("window", n, p))
@@ -454,9 +470,7 @@ def run_windowed_jax(net, sync, op, msize, nrep, win_size,
 
 
 def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
-                            ranks=None,
-                            use_pallas: bool | None = None
-                            ) -> "list[FusedWindowRun]":
+                            ranks=None) -> "list[FusedWindowRun]":
     """Measure one case across all launch epochs in fused device programs.
 
     ``nets[e] / syncs[e] / ops[e]`` are epoch ``e``'s simulator objects (one
@@ -492,8 +506,6 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
                 "engine='jax' requires affine clocks (rw_sigma == 0); use "
                 "engine='batch_rw' (or 'auto') for random-walk clocks")
     jax, sample_epochs, window_fused = _jitted_fused()
-    if use_pallas is None:
-        use_pallas = _use_pallas_default()
     if nrep <= 0:
         return [FusedWindowRun(times=np.empty(0),
                                errors=np.empty(0, dtype=np.int64))
@@ -534,8 +546,7 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
     def put(a):
         return jax.device_put(a, sharding) if sharding is not None else a
 
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with x64():
         durations = None
         for j in range(nterms):
             subs = [term_lists[e][j][0] for e in range(E)]
@@ -544,11 +555,11 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
                               for sub, net in zip(subs, nets)])
             ar_state = np.array([sub._ar_state for sub in subs])
             s0 = subs[0]
-            _STATS.count(("sample_epochs", E, n, use_pallas))
+            _STATS.count(("sample_epochs", E, n))
             dur, s_last = sample_epochs(
                 seeds, j, t0_op, ar_state, s0.noise_sigma, s0.autocorr,
                 s0.tail_prob, s0.tail_shift, s0.spike_prob, s0.spike_scale,
-                nrep, n=n, use_pallas=use_pallas)
+                nrep, n=n)
             s_last = np.asarray(s_last)
             for e, sub in enumerate(subs):
                 sub._ar_state = float(s_last[e])

@@ -41,6 +41,28 @@ def test_backends_satisfy_protocol():
         assert fs.fingerprint()
 
 
+def test_sim_backend_factors_name_the_device_only_for_the_jit_engine(
+        monkeypatch):
+    """engine='jax' runs on JAX's default device, and its factor set says
+    which; the numpy engines record the simulator and never query a device
+    (a query would claim a TPU for this process)."""
+    import jax
+
+    design = ExperimentDesign(n_launch_epochs=2, nrep=5)
+    fs = _sim(engine="jax").factors(design)
+    assert (fs.backend, fs.device_kind) == (jax.default_backend(),
+                                            jax.devices()[0].device_kind)
+
+    def no_device(*a, **k):
+        raise AssertionError("the numpy simulator queried a device")
+
+    monkeypatch.setattr(jax, "devices", no_device)
+    monkeypatch.setattr(jax, "default_backend", no_device)
+    fs = _sim().factors(design)
+    assert (fs.backend, fs.device_kind) == ("sim", "simnet")
+    assert "capture_failure" not in dict(fs.extra)
+
+
 def test_sim_backend_records_resolved_engine_meta():
     """Each record carries the engine that actually ran — ``auto`` on
     affine clocks resolves to ``batch``, and on random-walk clocks to
@@ -310,8 +332,7 @@ def test_end_to_end_sim_and_kernel_backends_compose(tmp_path):
     )
     backends = {
         "sim": _sim(seed0=50),     # unknown op name -> generic cost model
-        "kernel": KernelBackend(impl="pallas", batch=1, heads=2, head_dim=16,
-                                interpret=True),
+        "kernel": KernelBackend(impl="pallas", batch=1, heads=2, head_dim=16),
     }
     stores = {}
     for label, backend in backends.items():
@@ -349,3 +370,21 @@ def test_jax_backend_collectives_multi_device(tmp_path):
         med = table.medians(case)
         assert med.size == 2
         assert np.all(med > 0)
+
+
+@pytest.mark.jaxdevices(4)
+@pytest.mark.parametrize("op", ["psum", "all_gather", "all_to_all"])
+def test_jax_backend_input_is_placed_per_device(op):
+    """The collective's input is sharded over the mesh before the timed
+    call — one payload on each device, none left on the default device for
+    pmap to scatter — and the collective's output equals numpy's."""
+    import jax
+
+    backend = JaxBackend(n_devices=4)
+    x = backend._place_input(op, 4096, 4)
+    shards = x.addressable_shards
+    assert [s.device for s in shards] == jax.devices()[:4]
+    assert [s.index[0] for s in shards] == [slice(i, i + 1)
+                                            for i in range(4)]
+    assert all(s.data.shape == (1,) + x.shape[1:] for s in shards)
+    assert backend.check(op, 4096) == 0.0

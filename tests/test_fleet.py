@@ -19,7 +19,7 @@ from repro.campaign import (Campaign, CampaignSpec, ResultStore, SimBackend,
                             SweepScheduler)
 from repro.core import RetryBudgetExceeded, RetryPolicy, retry_call
 from repro.core.design import (ExperimentDesign, MeasurementRecord, TestCase,
-                               map_parallel)
+                               map_parallel, run_design)
 from repro.fleet import (CrashFault, FaultPlan, FaultyBackend, FleetConfig,
                          FleetScheduler, LeaseQueue, TransientFault,
                          merge_stores)
@@ -200,6 +200,30 @@ def test_map_parallel_stall_raises_timeout_naming_in_flight():
     with pytest.raises(TimeoutError, match="in flight"):
         map_parallel(_mp_hang, [(1,), (2,)], n_workers=2, timeout=0.5)
     assert time.time() - t0 < 30   # the hung workers were actually killed
+
+
+def test_workers_refused_when_this_process_holds_a_tpu(monkeypatch,
+                                                       tmp_path):
+    """A TPU belongs to one process: with the platform probe reporting a
+    held chip, every path that starts worker processes raises before any
+    worker exists — map_parallel, run_design's epoch fan-out and the
+    fleet's multi-process mode — and the in-process paths still run."""
+    import repro.core.design as design_mod
+
+    assert design_mod.holds_tpu() is False      # the CPU test process
+    monkeypatch.setattr(design_mod, "holds_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="holds a TPU"):
+        map_parallel(_mp_ret, [(1,), (2,)], n_workers=2)
+    spec, backend = _tiny_sweep(axes=("tuning",), n_launch_epochs=2, nrep=8)
+    with pytest.raises(RuntimeError, match="holds a TPU"):
+        run_design(ExperimentDesign(n_launch_epochs=2, nrep=5), backend,
+                   cases=[TestCase("allreduce", 512)], n_workers=2)
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        FleetScheduler(spec, backend, ResultStore(tmp_path / "f.jsonl"),
+                       FleetConfig(n_workers=2, poll_s=0.02)).run()
+    res = FleetScheduler(spec, backend, ResultStore(tmp_path / "s.jsonl"),
+                         _fast_fleet()).run()
+    assert res.n_cells_measured == 2 and not res.quarantined
 
 
 # ---------------------------------------------------------------------------

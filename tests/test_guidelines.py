@@ -254,7 +254,7 @@ def test_killed_guideline_campaign_resumes_missing_cells_only(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_kernel_backend_impl_tags_and_composites():
-    backend = KernelBackend(batch=1, heads=2, head_dim=16, interpret=True)
+    backend = KernelBackend(batch=1, heads=2, head_dim=16)
     ctx = backend.make_epoch(0)
     t_ref = backend.measure(ctx, TestCase("flash_attention#ref", 64), 2)
     assert t_ref.size == 2 and np.all(t_ref > 0)

@@ -79,6 +79,33 @@ def test_flash_attention_decode_offset_kvlen():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("window", [None, 64])
+def test_flash_attention_grad_matches_reference(window):
+    """The kernel is forward-only; its VJP is the reference's, so a train
+    step through it gets the reference's gradients."""
+    q, k, v = _t(1, 128, 4, 32), _t(1, 128, 2, 32), _t(1, 128, 2, 32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, window=window, block_q=64, block_k=64, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: flash_attention_ref(
+        q, k, v, window=window)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_flash_attention_block_must_divide_sequence():
+    """A sequence no multiple-of-8 block divides raises: the kernel never
+    hands a shape to its reference in silence."""
+    q = _t(1, 1001, 2, 32)
+    with pytest.raises(ValueError, match="divides"):
+        flash_attention(q, q, q, block_q=512, block_k=512, interpret=True)
+
+
 def test_flash_attention_block_size_invariance():
     q, k, v = _t(1, 512, 4, 64), _t(1, 512, 2, 64), _t(1, 512, 2, 64)
     outs = [flash_attention(q, k, v, block_q=bq, block_k=bk, interpret=True)
@@ -169,44 +196,18 @@ def test_ssm_block_decode_matches_train():
 
 
 # ---------------------------------------------------------------------------
-# sim_scan: fused duration-sampling kernel (repro.simjax hot path)
+# sim_scan: the simulator's duration sampler (repro.simjax hot path)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("coeff", [0.35, 0.0, -0.5, 0.9])
-@pytest.mark.parametrize("n", [32, 1000])
-def test_sim_scan_kernel_matches_ref(coeff, n):
-    """Pallas fused AR(1)+mixture == the associative_scan oracle, across
-    chunk-aligned and padded lengths and the coeff operating range."""
-    from jax.experimental import enable_x64
-
-    from repro.kernels.sim_scan.kernel import sim_durations_scan
-    from repro.kernels.sim_scan.ref import sim_durations_ref
-
-    with enable_x64():
-        key = jax.random.PRNGKey(coeff is None or int(abs(coeff) * 100))
-        ks = jax.random.split(key, 4)
-        eps = 0.04 * jax.random.normal(ks[0], (n,), jnp.float64)
-        u = [jax.random.uniform(k, (n,), jnp.float64) for k in ks[1:]]
-        kw = dict(coeff=coeff, state=0.1, t0=22e-6, tail_prob=0.08,
-                  tail_shift=0.35, spike_prob=0.003, spike_scale=8.0)
-        t_ref, s_ref = sim_durations_ref(eps, *u, **kw)
-        t_ker, s_ker = sim_durations_scan(eps, *u, **kw)
-        np.testing.assert_allclose(np.asarray(t_ker), np.asarray(t_ref),
-                                   rtol=1e-12, atol=1e-18)
-        np.testing.assert_allclose(np.asarray(s_ker), np.asarray(s_ref),
-                                   rtol=1e-12, atol=1e-14)
-
-
 def test_sim_scan_ref_matches_numpy_ar1_filter():
-    """The jnp oracle reproduces the numpy engine's _ar1_filter math."""
-    from jax.experimental import enable_x64
-
+    """The jnp sampler reproduces the numpy engine's _ar1_filter math."""
     from repro.core.mpi_ops import _ar1_filter
     from repro.kernels.sim_scan.ref import sim_durations_ref
+    from repro.simjax.engine import x64
 
     rng = np.random.default_rng(7)
     eps = rng.normal(0.0, 0.04, size=500)
-    with enable_x64():
+    with x64():
         zeros = jnp.zeros(500, jnp.float64)
         _, s = sim_durations_ref(jnp.asarray(eps), zeros, zeros, zeros,
                                  coeff=0.35, state=0.7, t0=1.0,
