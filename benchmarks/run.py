@@ -478,9 +478,8 @@ def _run_suite(ap, args) -> None:
         jit1 = engine_stats()
         nd = jit1["n_dispatches"] - jit0["n_dispatches"]
         if nd > 0:
-            nt = jit1["n_traces"] - jit0["n_traces"]
-            entry["jit"] = dict(n_traces=nt, n_dispatches=nd,
-                                cache_hit_rate=round(1.0 - nt / nd, 4))
+            entry["jit"] = dict(n_traces=jit1["n_traces"] - jit0["n_traces"],
+                                n_dispatches=nd)
         report["benches"].append(entry)
     report["total_seconds"] = round(time.time() - t_suite, 3)
     report["total_nrep"] = NREP_SPENT.read() - nrep_suite

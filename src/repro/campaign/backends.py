@@ -30,6 +30,7 @@ from typing import Any, Protocol, runtime_checkable
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.design import ExperimentDesign, TestCase
 from repro.core.factors import FactorSet, capture_factors
 from repro.core.mpi_ops import make_composite_op
@@ -164,6 +165,7 @@ class _SimEpoch:
     """One simulated launch epoch: a fresh cluster, synchronized clocks,
     and a lazily-built cost model per op name."""
 
+    @telemetry.spanned("epoch_build")
     def __init__(self, backend: "SimBackend", epoch: int):
         self.backend = backend
         self.net = SimNet(
@@ -172,8 +174,9 @@ class _SimEpoch:
             else None,
             seed=backend.seed0 + 1000 * epoch)
         sync_kw = _filter_sync_kw(backend.sync_name, backend.sync_kw)
-        self.sync = make_sync(backend.sync_name,
-                              **sync_kw).synchronize(self.net)
+        with telemetry.span("clock_sync"):
+            self.sync = make_sync(backend.sync_name,
+                                  **sync_kw).synchronize(self.net)
         # Resolve once per epoch: what will actually run. A substitution
         # (jax requested but unusable) is never silent — it is warned once
         # per campaign and recorded per record (`meta["engine"]`).

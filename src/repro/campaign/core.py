@@ -28,6 +28,7 @@ from __future__ import annotations
 import platform
 from dataclasses import dataclass, field
 
+from repro.core import telemetry
 from repro.core.design import (NREP_SPENT, ExperimentDesign,
                                MeasurementRecord, ResultTable, TestCase,
                                analyze_records, case_orders, measure_case)
@@ -39,25 +40,33 @@ from .store import ResultStore, StoreSnapshot
 __all__ = ["CampaignSpec", "CampaignResult", "Campaign"]
 
 
-def _engine_stats() -> dict:
-    """Cumulative jit telemetry of the simulation engine (zeros when jax
-    is absent — `engine_stats` itself never imports jax)."""
+def _jit_counts() -> dict:
+    """Cumulative jit telemetry: the simulation engine's dispatches and
+    shape keys (`engine_stats`, which never imports jax) and the compiles
+    and persistent-cache reads the telemetry counters saw (zero before
+    anything used jax)."""
     from repro.simjax import engine_stats
 
-    return engine_stats()
+    c = telemetry.counters()
+    return dict(engine_stats(), n_compiles=c.get("compiles", 0),
+                n_cache_reads=c.get("compile_cache_reads", 0),
+                compile_s=c.get("compile_s", 0.0))
 
 
 def _jit_delta(before: dict, after: dict) -> dict | None:
-    """This campaign's share of the jit telemetry: dispatches issued and
-    traces newly compiled while it ran, plus the trace-cache hit rate
-    (dispatches served without a fresh compile). None when the campaign
-    never touched the jit engine — meta stays clean for other backends."""
-    nd = after["n_dispatches"] - before["n_dispatches"]
-    if nd <= 0:
+    """This campaign's share of the jit telemetry: simulator dispatches
+    issued and shape keys newly traced (`n_traces`), and every executable
+    compiled or read from the persistent cache while it ran
+    (`n_compiles`, `compile_s`; this alone sees a meter's re-jits under
+    `clear_caches`), of which `n_cache_reads` were read, so that
+    `n_compiles - n_cache_reads` were compiled. None when the campaign
+    neither dispatched to the simulator nor compiled — meta stays clean
+    for other backends."""
+    d = {k: after[k] - before[k] for k in after}
+    if d["n_dispatches"] <= 0 and d["n_compiles"] <= 0:
         return None
-    nt = after["n_traces"] - before["n_traces"]
-    return dict(n_traces=nt, n_dispatches=nd,
-                cache_hit_rate=round(1.0 - nt / nd, 4))
+    d["compile_s"] = round(d["compile_s"], 6)
+    return d
 
 
 @dataclass
@@ -109,6 +118,7 @@ class Campaign:
         self.store = store
         self.archive = archive
 
+    @telemetry.spanned("campaign")
     def run(self, snapshot: StoreSnapshot | None = None,
             on_record=None, epochs=None) -> CampaignResult:
         """Execute (or resume) the campaign. ``snapshot`` — a
@@ -158,7 +168,7 @@ class Campaign:
         records: list[MeasurementRecord] = []
         n_measured = n_resumed = 0
         orders = list(enumerate(case_orders(design, cases)))
-        stats0 = _engine_stats()
+        stats0 = _jit_counts()
 
         # Fused execution: a backend advertising `measure_epochs` gets the
         # whole window's pending work in one call and may batch epochs into
@@ -220,7 +230,7 @@ class Campaign:
 
         table = analyze_records(records, design.outlier_filter)
         meta = spec.meta()
-        jit = _jit_delta(stats0, _engine_stats())
+        jit = _jit_delta(stats0, _jit_counts())
         if jit is not None:
             meta["jit"] = jit
         if self.archive is not None:

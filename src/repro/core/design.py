@@ -37,6 +37,7 @@ from typing import Any, Callable, Iterable
 
 import numpy as np
 
+from . import telemetry
 from .stats import relative_ci_width, tukey_filter
 
 __all__ = [
@@ -63,18 +64,16 @@ class _NrepCounter:
     Wall-clock seconds depend on the machine; *repetitions spent* is the
     machine-independent cost a budgeted sweep actually saves — the
     benchmark harness snapshots this around each bench to report
-    ``nrep_total`` next to seconds."""
+    ``nrep_total`` next to seconds. A view of the ``nrep`` counter of
+    :mod:`repro.core.telemetry`."""
 
-    __slots__ = ("total",)
-
-    def __init__(self) -> None:
-        self.total = 0
+    __slots__ = ()
 
     def add(self, n: int) -> None:
-        self.total += int(n)
+        telemetry.count("nrep", int(n))
 
     def read(self) -> int:
-        return self.total
+        return telemetry.counters().get("nrep", 0)
 
 
 #: The process-wide repetition counter (see :class:`_NrepCounter`).

@@ -29,6 +29,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.core import telemetry
+
 __all__ = ["timed_calls", "JaxEpochContext", "make_jax_measure", "MeterConfig",
            "use_compile_cache"]
 
@@ -54,21 +56,29 @@ def use_compile_cache(root: str) -> str:
     return path
 
 
-def timed_calls(fn: Callable[[], Any], nrep: int, warmup: int = 3) -> np.ndarray:
-    """Time ``nrep`` calls of a nullary ``fn`` whose result supports
-    ``block_until_ready`` (or is a pytree of such)."""
+def _warm(fn: Callable[[], Any], n: int) -> None:
     import jax
 
-    def _block(x):
-        return jax.block_until_ready(x)
+    for _ in range(n):
+        jax.block_until_ready(fn())
 
-    for _ in range(warmup):
-        _block(fn())
+
+def timed_calls(fn: Callable[[], Any], nrep: int, warmup: int = 3) -> np.ndarray:
+    """Time ``nrep`` calls of a nullary ``fn`` whose result supports
+    ``block_until_ready`` (or is a pytree of such). Under a profiler trace
+    each timed call, dispatch and block together, is a ``timed_call``
+    span (:mod:`repro.core.telemetry`)."""
+    import jax
+
+    telemetry.watch_compiles()
+    _block = jax.block_until_ready
+    _warm(fn, warmup)
     out = np.empty(nrep)
     for i in range(nrep):
-        t0 = time.perf_counter_ns()
-        _block(fn())
-        out[i] = (time.perf_counter_ns() - t0) * 1e-9
+        with telemetry.span("timed_call"):
+            t0 = time.perf_counter_ns()
+            _block(fn())
+            out[i] = (time.perf_counter_ns() - t0) * 1e-9
     return out
 
 
@@ -88,8 +98,11 @@ class JaxEpochContext:
     epoch already amortized.
     """
 
+    @telemetry.spanned("epoch_build")
     def __init__(self, build: Callable[[int], dict[str, Callable[[], Any]]],
                  epoch: int, config: MeterConfig):
+        # before the epoch's first jit, so that its compiles are counted
+        telemetry.watch_compiles()
         self.epoch = epoch
         self.config = config
         if config.epoch_isolation == "clear_caches":
@@ -102,9 +115,13 @@ class JaxEpochContext:
 
     def measure(self, name: str, nrep: int) -> np.ndarray:
         fn = self.callables[name]
-        warmup = 0 if name in self._warmed else self.config.warmup
-        self._warmed.add(name)
-        return timed_calls(fn, nrep, warmup=warmup)
+        if name not in self._warmed:
+            self._warmed.add(name)
+            # the epoch's first calls: re-jit, then a compile or a read of
+            # the persistent compilation cache
+            with telemetry.span("warmup"):
+                _warm(fn, self.config.warmup)
+        return timed_calls(fn, nrep, warmup=0)
 
 
 def make_jax_measure(build: Callable[[int], dict[str, Callable[[], Any]]],

@@ -37,7 +37,10 @@ statistically indistinguishable from the per-epoch engine's rather than
 bit-identical (the sampled *durations* remain bit-identical).
 
 Both engines meter themselves: :func:`engine_stats` counts compiled traces
-and dispatches, so "one trace per campaign" is a measured quantity.
+and dispatches, so "one trace per campaign" is a measured quantity. Under
+a profiler trace, each engine call is a ``sim_engine`` span
+(:mod:`repro.core.telemetry`) and each host read of a device result inside
+it a ``sim_wait`` span.
 """
 
 from __future__ import annotations
@@ -47,6 +50,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.window import START_LATE, TOOK_TOO_LONG, WindowRun
 
 __all__ = [
@@ -95,21 +99,23 @@ def _bucket(nrep: int) -> int:
 
 
 class _EngineStats:
-    """Process-global jit telemetry: every device dispatch is counted, and
-    trace keys (jitted function x static/shape signature) are collected so
-    ``n_traces`` measures distinct compilations. Monotone by design — like
-    the jit cache it mirrors — so a snapshot-delta of the counts is the
-    per-campaign telemetry."""
+    """Process-global jit telemetry, kept in :mod:`repro.core.telemetry`'s
+    counters ``sim_dispatches`` and ``sim_traces``: every device dispatch
+    is counted, and trace keys (jitted function x static/shape signature)
+    are collected so ``sim_traces`` counts distinct compiled signatures.
+    Monotone by design — like the jit cache it mirrors — so a
+    snapshot-delta of the counts is the per-campaign telemetry."""
 
-    __slots__ = ("dispatches", "trace_keys")
+    __slots__ = ("trace_keys",)
 
     def __init__(self) -> None:
-        self.dispatches = 0
         self.trace_keys: set = set()
 
     def count(self, trace_key: tuple) -> None:
-        self.dispatches += 1
-        self.trace_keys.add(trace_key)
+        telemetry.count("sim_dispatches")
+        if trace_key not in self.trace_keys:
+            self.trace_keys.add(trace_key)
+            telemetry.count("sim_traces")
 
 
 _STATS = _EngineStats()
@@ -119,12 +125,13 @@ def engine_stats() -> dict:
     """Cumulative jit telemetry: ``n_traces`` (distinct compiled
     signatures) and ``n_dispatches`` (device calls). Campaigns and the
     bench harness snapshot this before/after and report the delta."""
-    return {"n_traces": len(_STATS.trace_keys),
-            "n_dispatches": _STATS.dispatches}
+    c = telemetry.counters()
+    return {"n_traces": c.get("sim_traces", 0),
+            "n_dispatches": c.get("sim_dispatches", 0)}
 
 
 def reset_engine_stats() -> None:
-    _STATS.dispatches = 0
+    telemetry.reset_counters("sim_dispatches", "sim_traces")
     _STATS.trace_keys.clear()
 
 
@@ -166,6 +173,8 @@ def _cores():
                                 "importable in this environment")
     import jax
     import jax.numpy as jnp
+
+    telemetry.watch_compiles()
 
     def sample(key, t0_op, ar_state, noise_sigma, autocorr, tail_prob,
                tail_shift, spike_prob, spike_scale, *, n):
@@ -403,6 +412,7 @@ def _terms(op, p: int, msize: int):
     return out
 
 
+@telemetry.spanned("sim_engine")
 def run_windowed_jax(net, sync, op, msize, nrep, win_size,
                      ranks=None) -> WindowRun:
     """JAX port of ``run_windowed``'s batch engine (affine clocks only).
@@ -449,26 +459,28 @@ def run_windowed_jax(net, sync, op, msize, nrep, win_size,
                             sub._ar_state, sub.noise_sigma, sub.autocorr,
                             sub.tail_prob, sub.tail_shift, sub.spike_prob,
                             sub.spike_scale, n=n)
-            sub._ar_state = float(s[nrep - 1])
+            with telemetry.span("sim_wait"):
+                sub._ar_state = float(s[nrep - 1])
             durations = dur if durations is None else durations + dur
         _STATS.count(("window", n, p))
         times, errors, sg, eg, st, et = window(
             durations, jax.random.fold_in(key, len(terms)), t0, off, skew,
             scale, slope, intercept, init_t, op.rank_imbalance, start_time,
             win_size)
-        et = np.asarray(et, dtype=np.float64)[:nrep]
+        with telemetry.span("sim_wait"):
+            run = WindowRun(
+                times=np.asarray(times, dtype=np.float64)[:nrep],
+                errors=np.asarray(errors, dtype=np.int64)[:nrep],
+                start_global_est=np.asarray(sg, dtype=np.float64)[:nrep],
+                end_global_est=np.asarray(eg, dtype=np.float64)[:nrep],
+                start_true=np.asarray(st, dtype=np.float64)[:nrep],
+                end_true=np.asarray(et, dtype=np.float64)[:nrep])
 
-    net.t[ranks] = et[nrep - 1]
-    return WindowRun(
-        times=np.asarray(times, dtype=np.float64)[:nrep],
-        errors=np.asarray(errors, dtype=np.int64)[:nrep],
-        start_global_est=np.asarray(sg, dtype=np.float64)[:nrep],
-        end_global_est=np.asarray(eg, dtype=np.float64)[:nrep],
-        start_true=np.asarray(st, dtype=np.float64)[:nrep],
-        end_true=et,
-    )
+    net.t[ranks] = run.end_true[nrep - 1]
+    return run
 
 
+@telemetry.spanned("sim_engine")
 def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
                             ranks=None) -> "list[FusedWindowRun]":
     """Measure one case across all launch epochs in fused device programs.
@@ -560,7 +572,8 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
                 seeds, j, t0_op, ar_state, s0.noise_sigma, s0.autocorr,
                 s0.tail_prob, s0.tail_shift, s0.spike_prob, s0.spike_scale,
                 nrep, n=n)
-            s_last = np.asarray(s_last)
+            with telemetry.span("sim_wait"):
+                s_last = np.asarray(s_last)
             for e, sub in enumerate(subs):
                 sub._ar_state = float(s_last[e])
             durations = dur if durations is None else durations + dur
@@ -580,8 +593,9 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
                 put(scale[e]), put(slope[e]), put(intercept[e]),
                 put(init_t[e]), ops[e].rank_imbalance,
                 float(start_times[e]), win_size, nrep, ch=ch)
-            nets[e].t[ranks] = np.asarray(et_last, dtype=np.float64)
-            runs.append(FusedWindowRun(
-                times=np.asarray(times, dtype=np.float64)[:nrep],
-                errors=np.asarray(errors, dtype=np.int64)[:nrep]))
+            with telemetry.span("sim_wait"):
+                nets[e].t[ranks] = np.asarray(et_last, dtype=np.float64)
+                runs.append(FusedWindowRun(
+                    times=np.asarray(times, dtype=np.float64)[:nrep],
+                    errors=np.asarray(errors, dtype=np.int64)[:nrep]))
     return runs

@@ -240,7 +240,8 @@ def test_fused_campaign_equivalent_resumable_and_metered():
     assert all(r.meta["nrep_used"] == r.times.size == 50 for r in rf.records)
 
     jit = rf.meta["jit"]
-    assert jit["n_dispatches"] > 0 and 0.0 <= jit["cache_hit_rate"] <= 1.0
+    assert jit["n_dispatches"] > 0 and jit["n_compiles"] >= 0
+    assert jit["compile_s"] >= 0.0 and "cache_hit_rate" not in jit
     assert rf.meta["jit"]["n_dispatches"] < ru.meta["jit"]["n_dispatches"]
 
     import tempfile
