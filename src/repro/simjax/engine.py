@@ -17,11 +17,20 @@ One measurement call lowers to two jitted programs:
 
 Host-side work per call is O(p): clock/sync model coefficients, per-term
 epoch biases (through the same :func:`~repro.core.clocks.derive_stream`
-helper as the numpy engines) and the AR(1) carry in/out. Small ``nrep``
-are padded to a power-of-two bucket so adaptive campaigns hit a handful of
-compiled shapes instead of recompiling per top-up; padded windows are
-computed and discarded (the entry recurrence is forward-only, so the first
-``nrep`` windows are unaffected).
+helper as the numpy engines), the AR(1) carry in/out, and the PRNG keys,
+derived on the host by :func:`_fold_in` (bit-identical to
+``jax.random.fold_in(jax.random.PRNGKey(seed), j)``). Between its device
+programs the host issues no eager JAX operation: the terms' durations are
+summed (and the fused engine's lanes padded and split) by one jitted
+helper, and every device result of the call is read in one
+``jax.device_get`` after all of the call's programs are dispatched — one
+round-trip per call, unless a cost-model object appears in two terms of
+the op, whose AR(1) carry must come back before the next term samples.
+
+Small ``nrep`` are padded to a power-of-two bucket so adaptive campaigns
+hit a handful of compiled shapes instead of recompiling per top-up; padded
+windows are computed and discarded (the entry recurrence is forward-only,
+so the first ``nrep`` windows are unaffected).
 
 :func:`run_windowed_epochs_jax` is the campaign-resident variant: duration
 sampling is vmapped over a per-epoch key axis (``fold_in`` of each epoch's
@@ -37,10 +46,11 @@ statistically indistinguishable from the per-epoch engine's rather than
 bit-identical (the sampled *durations* remain bit-identical).
 
 Both engines meter themselves: :func:`engine_stats` counts compiled traces
-and dispatches, so "one trace per campaign" is a measured quantity. Under
-a profiler trace, each engine call is a ``sim_engine`` span
-(:mod:`repro.core.telemetry`) and each host read of a device result inside
-it a ``sim_wait`` span.
+and dispatches of the sample and window programs, so "one trace per
+campaign" is a measured quantity, and the ``sim_host_reads`` counter
+(:mod:`repro.core.telemetry`) counts the blocking host reads of device
+results. Under a profiler trace, each engine call is a ``sim_engine`` span
+and each such read a ``sim_wait`` span.
 """
 
 from __future__ import annotations
@@ -98,6 +108,35 @@ def _bucket(nrep: int) -> int:
     return n
 
 
+_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def _fold_in(seed, data) -> np.ndarray:
+    """``jax.random.fold_in(jax.random.PRNGKey(seed), data)`` computed on
+    the host, broadcast over ``seed`` and ``data`` (each in [0, 2^32)).
+
+    ``PRNGKey(seed)`` is the raw key ``(0, seed)`` and ``fold_in`` hashes
+    the counter ``(0, data)`` under it with Threefry-2x32 (20 rounds), so
+    this is that hash in numpy ``uint32`` arithmetic, which wraps as the
+    device's does. Returns raw keys, ``uint32`` of shape
+    ``broadcast(seed, data).shape + (2,)``."""
+    k1, x1 = np.broadcast_arrays(np.asarray(seed, dtype=np.uint32),
+                                 np.asarray(data, dtype=np.uint32))
+    shape = k1.shape
+    # 1-D, so every wrap is array arithmetic (numpy warns on scalar wraps)
+    k1, x1 = k1.reshape(-1), x1.reshape(-1)
+    ks = (np.uint32(0), k1, k1 ^ np.uint32(0x1BD11BDA))
+    x0 = np.zeros_like(x1)
+    x1 = x1 + k1
+    for i in range(5):
+        for r in _ROTATIONS[i % 2]:
+            x0 = x0 + x1
+            x1 = ((x1 << r) | (x1 >> (32 - r))) ^ x0
+        x0 = x0 + ks[(i + 1) % 3]
+        x1 = x1 + ks[(i + 2) % 3] + np.uint32(i + 1)
+    return np.stack([x0, x1], axis=-1).reshape(shape + (2,))
+
+
 class _EngineStats:
     """Process-global jit telemetry, kept in :mod:`repro.core.telemetry`'s
     counters ``sim_dispatches`` and ``sim_traces``: every device dispatch
@@ -133,6 +172,24 @@ def engine_stats() -> dict:
 def reset_engine_stats() -> None:
     telemetry.reset_counters("sim_dispatches", "sim_traces")
     _STATS.trace_keys.clear()
+
+
+def _read(jax, tree):
+    """One blocking host read of the device results in ``tree`` (a
+    ``sim_wait`` span, counted by ``sim_host_reads``): every array's copy
+    starts before the first is waited for."""
+    with telemetry.span("sim_wait"):
+        out = jax.device_get(tree)
+    telemetry.count("sim_host_reads")
+    return out
+
+
+def _chained(term_subs) -> bool:
+    """Whether a cost-model object appears in two terms (``term_subs[j]``
+    lists term ``j``'s objects): its AR(1) carry out of the earlier term is
+    the later term's carry in, so it must be read back in between."""
+    ids = [{id(s) for s in subs} for subs in term_subs]
+    return sum(map(len, ids)) > len(set().union(*ids))
 
 
 def _chunk_for(p: int, n: int) -> int:
@@ -366,6 +423,30 @@ def _jitted_fused():
             jax.jit(window_fused, static_argnames=("ch",)))
 
 
+@functools.lru_cache(maxsize=1)
+def _jitted_lanes():
+    """Build (once) the jitted helper that turns the terms' sampled
+    durations into the window programs' input, so the host issues no eager
+    op for it: ``lanes(durs, npad=)`` sums the tuple ``durs`` in term order;
+    an ``(E, n)`` sum is padded to ``npad`` with each lane's last value
+    (padded windows are computed and discarded) and returned as ``E``
+    ``(npad,)`` lanes, an ``(n,)`` sum is returned as it is."""
+    jax, _, _ = _cores()
+    import jax.numpy as jnp
+
+    def lanes(durs, *, npad):
+        d = functools.reduce(jnp.add, durs)
+        if d.ndim == 1:
+            return d
+        E, n = d.shape
+        if npad > n:
+            d = jnp.concatenate(
+                [d, jnp.broadcast_to(d[:, n - 1:n], (E, npad - n))], axis=1)
+        return tuple(d[e] for e in range(E))
+
+    return jax.jit(lanes, static_argnames=("npad",))
+
+
 @dataclass
 class FusedWindowRun:
     """O(nrep) outputs of one fused epoch. The ``(nrep, p)`` global-time
@@ -449,33 +530,38 @@ def run_windowed_jax(net, sync, op, msize, nrep, win_size,
     intercept = np.array([sync.models[r].intercept for r in ranks])
     init_t = np.array([sync.initial_times[r] for r in ranks])
 
+    # keys j < len(terms) sample the terms, key len(terms) the window
+    keys = _fold_in(seed, np.arange(len(terms) + 1))
+    chained = _chained([[sub] for sub, _, _ in terms])
     with x64():
-        key = jax.random.PRNGKey(seed)
-        durations = None
+        durs, carries = [], []
         for j, (sub, tp, tm) in enumerate(terms):
             t0_op = sub.base_time(tp, tm) * sub._bias_for(net)
             _STATS.count(("sample", n))
-            dur, s = sample(jax.random.fold_in(key, j), t0_op,
-                            sub._ar_state, sub.noise_sigma, sub.autocorr,
-                            sub.tail_prob, sub.tail_shift, sub.spike_prob,
-                            sub.spike_scale, n=n)
-            with telemetry.span("sim_wait"):
-                sub._ar_state = float(s[nrep - 1])
-            durations = dur if durations is None else durations + dur
+            dur, s = sample(keys[j], t0_op, sub._ar_state, sub.noise_sigma,
+                            sub.autocorr, sub.tail_prob, sub.tail_shift,
+                            sub.spike_prob, sub.spike_scale, n=n)
+            durs.append(dur)
+            if chained:
+                sub._ar_state = float(_read(jax, s)[nrep - 1])
+            else:
+                carries.append(s)
+        durations = durs[0] if len(durs) == 1 \
+            else _jitted_lanes()(tuple(durs), npad=n)
         _STATS.count(("window", n, p))
-        times, errors, sg, eg, st, et = window(
-            durations, jax.random.fold_in(key, len(terms)), t0, off, skew,
-            scale, slope, intercept, init_t, op.rank_imbalance, start_time,
-            win_size)
-        with telemetry.span("sim_wait"):
-            run = WindowRun(
-                times=np.asarray(times, dtype=np.float64)[:nrep],
-                errors=np.asarray(errors, dtype=np.int64)[:nrep],
-                start_global_est=np.asarray(sg, dtype=np.float64)[:nrep],
-                end_global_est=np.asarray(eg, dtype=np.float64)[:nrep],
-                start_true=np.asarray(st, dtype=np.float64)[:nrep],
-                end_true=np.asarray(et, dtype=np.float64)[:nrep])
-
+        out = window(durations, keys[-1], t0, off, skew, scale, slope,
+                     intercept, init_t, op.rank_imbalance, start_time,
+                     win_size)
+        carries, out = _read(jax, (carries, out))
+    for (sub, _, _), s in zip(terms, carries):
+        sub._ar_state = float(s[nrep - 1])
+    times, errors, sg, eg, st, et = out
+    run = WindowRun(times=np.asarray(times[:nrep], dtype=np.float64),
+                    errors=np.asarray(errors[:nrep], dtype=np.int64),
+                    start_global_est=np.asarray(sg[:nrep], dtype=np.float64),
+                    end_global_est=np.asarray(eg[:nrep], dtype=np.float64),
+                    start_true=np.asarray(st[:nrep], dtype=np.float64),
+                    end_true=np.asarray(et[:nrep], dtype=np.float64))
     net.t[ranks] = run.end_true[nrep - 1]
     return run
 
@@ -492,6 +578,13 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
     ``_cores`` sample program under the same per-epoch ``fold_in`` keys);
     the window recurrence dispatches per epoch — start times differ — but
     every dispatch reuses one chunked-scan trace per ``(p, shape-bucket)``.
+    Each epoch's window key is derived on the host (:func:`_fold_in` of
+    the epoch seed and the number of terms); the terms' durations are
+    summed, padded and split into per-epoch lanes by one jitted helper;
+    all ``E`` windows are dispatched before the call's one host read, which
+    takes every window's times, flags and end state and the terms' AR(1)
+    carries together (a cost-model object in two terms has its carry read
+    after its earlier term instead).
     Host-side RNG order per epoch (window seed, then per-term epoch biases)
     matches the per-epoch engine, and the AR(1) carry and ``net.t``
     writebacks land exactly as ``E`` sequential per-epoch calls would, so a
@@ -558,10 +651,13 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
     def put(a):
         return jax.device_put(a, sharding) if sharding is not None else a
 
+    term_subs = [[term_lists[e][j][0] for e in range(E)]
+                 for j in range(nterms)]
+    chained = _chained(term_subs)
+    window_keys = _fold_in(seeds, nterms)
     with x64():
-        durations = None
-        for j in range(nterms):
-            subs = [term_lists[e][j][0] for e in range(E)]
+        durs, carries = [], []
+        for j, subs in enumerate(term_subs):
             tp, tm = term_lists[0][j][1], term_lists[0][j][2]
             t0_op = np.array([sub.base_time(tp, tm) * sub._bias_for(net)
                               for sub, net in zip(subs, nets)])
@@ -572,30 +668,31 @@ def run_windowed_epochs_jax(nets, syncs, ops, msize, nrep, win_size,
                 seeds, j, t0_op, ar_state, s0.noise_sigma, s0.autocorr,
                 s0.tail_prob, s0.tail_shift, s0.spike_prob, s0.spike_scale,
                 nrep, n=n)
-            with telemetry.span("sim_wait"):
-                s_last = np.asarray(s_last)
-            for e, sub in enumerate(subs):
-                sub._ar_state = float(s_last[e])
-            durations = dur if durations is None else durations + dur
+            durs.append(dur)
+            if chained:
+                s_last = _read(jax, s_last)
+                for e, sub in enumerate(subs):
+                    sub._ar_state = float(s_last[e])
+            else:
+                carries.append(s_last)
 
-        import jax.numpy as jnp
-        if npad > n:
-            durations = jnp.concatenate(
-                [durations, jnp.broadcast_to(durations[:, n - 1:n],
-                                             (E, npad - n))], axis=1)
-        runs = []
+        lanes = _jitted_lanes()(tuple(durs), npad=npad)
+        outs = []
         for e in range(E):
-            key = jax.random.fold_in(jax.random.PRNGKey(int(seeds[e])),
-                                     nterms)
             _STATS.count(("window_fused", ch, npad, p))
-            times, errors, et_last = window_fused(
-                durations[e], key, put(t0[e]), put(off[e]), put(skew[e]),
-                put(scale[e]), put(slope[e]), put(intercept[e]),
-                put(init_t[e]), ops[e].rank_imbalance,
-                float(start_times[e]), win_size, nrep, ch=ch)
-            with telemetry.span("sim_wait"):
-                nets[e].t[ranks] = np.asarray(et_last, dtype=np.float64)
-                runs.append(FusedWindowRun(
-                    times=np.asarray(times, dtype=np.float64)[:nrep],
-                    errors=np.asarray(errors, dtype=np.int64)[:nrep]))
+            outs.append(window_fused(
+                lanes[e], window_keys[e], put(t0[e]), put(off[e]),
+                put(skew[e]), put(scale[e]), put(slope[e]),
+                put(intercept[e]), put(init_t[e]), ops[e].rank_imbalance,
+                float(start_times[e]), win_size, nrep, ch=ch))
+        carries, outs = _read(jax, (carries, outs))
+    for subs, s_last in zip(term_subs, carries):
+        for e, sub in enumerate(subs):
+            sub._ar_state = float(s_last[e])
+    runs = []
+    for e, (times, errors, et_last) in enumerate(outs):
+        nets[e].t[ranks] = np.asarray(et_last, dtype=np.float64)
+        runs.append(FusedWindowRun(
+            times=np.asarray(times[:nrep], dtype=np.float64),
+            errors=np.asarray(errors[:nrep], dtype=np.int64)))
     return runs

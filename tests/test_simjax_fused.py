@@ -18,8 +18,9 @@ import pytest
 
 from repro.campaign import (Campaign, CampaignSpec, ResultStore, SimBackend,
                             SweepScheduler, SweepSpec)
-from repro.core import (ExperimentDesign, FactorAxis, FactorGrid, TestCase,
-                        compare_tables, make_op, make_sync,
+from repro.core import (ExperimentDesign, FactorAxis, FactorGrid,
+                        SimCompositeOp, TestCase, compare_tables,
+                        make_composite_op, make_op, make_sync, telemetry,
                         wilcoxon_rank_sum)
 
 pytest.importorskip("jax")
@@ -33,6 +34,7 @@ NOISE_FREE = dict(noise_sigma=0.0, tail_prob=0.0, spike_prob=0.0,
 
 
 def _epochs(E, p=8, seed0=7, op="allreduce", **op_kw):
+    """Epoch ``e``'s net, sync and op; ``op`` is a name or a factory."""
     nets, syncs, ops = [], [], []
     for e in range(E):
         from repro.core import SimNet
@@ -40,7 +42,7 @@ def _epochs(E, p=8, seed0=7, op="allreduce", **op_kw):
         net = SimNet(p, seed=seed0 + 1000 * e)
         syncs.append(make_sync("hca", **SYNC_KW).synchronize(net))
         nets.append(net)
-        ops.append(make_op(op, **op_kw))
+        ops.append(op() if callable(op) else make_op(op, **op_kw))
     return nets, syncs, ops
 
 
@@ -101,6 +103,225 @@ def test_fused_strict_on_random_walk_clocks():
     with pytest.raises(SimJaxUnavailable):
         run_windowed_epochs_jax([net], [sync], [make_op("bcast")], 256, 10,
                                 400e-6)
+
+
+# ---------------------------------------------------------------------------
+# Host path: keys derived on the host, one read per call
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("j", range(4))
+@pytest.mark.parametrize("seed", [0, 1, 40_503, 7_654_321, 2**30 - 3,
+                                  1_234_567_891, 2**31 - 2, 2**31 - 1])
+def test_host_key_matches_eager_fold_in(seed, j):
+    import jax
+
+    from repro.simjax.engine import _fold_in
+
+    want = np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), j))
+    got = _fold_in(seed, j)
+    assert got.dtype == np.uint32 and np.array_equal(got, want)
+    # broadcast over seeds and over terms, as the engines call it
+    assert np.array_equal(_fold_in(np.array([seed, 5]), j)[0], want)
+    assert np.array_equal(_fold_in(seed, np.arange(4))[j], want)
+
+
+def _repeated():
+    """A composite whose two terms are one cost-model object: its AR(1)
+    carry runs from the first term into the second."""
+    op = make_op("bcast")
+    return SimCompositeOp(name="bcast+bcast*0.5",
+                          terms=((op, 1.0, 1.0), (op, 0.5, 1.0)))
+
+
+HOST_PATH_OPS = {
+    "single": lambda: make_op("allreduce"),
+    "composite": lambda: make_composite_op("scatter+allgather"),
+    "repeated": _repeated,
+}
+
+
+def _coeffs(net, sync, ranks):
+    """The window programs' per-rank inputs after the durations and key."""
+    return [np.asarray(net.t[ranks], dtype=np.float64),
+            np.array([net.clocks[r].offset for r in ranks]),
+            np.array([net.clocks[r].skew for r in ranks]),
+            np.array([net.clocks[r].scale_error for r in ranks]),
+            np.array([sync.models[r].slope for r in ranks]),
+            np.array([sync.models[r].intercept for r in ranks]),
+            np.array([sync.initial_times[r] for r in ranks])]
+
+
+def _eager_fused(nets, syncs, ops, msize, nrep, ws):
+    """A fused call as the engine made it with eager device keys: the
+    jitted programs called directly, keys from ``jax.random``, durations
+    summed, padded and indexed eagerly. Returns (times, errors) per epoch
+    and writes ``net.t`` and the AR(1) states back."""
+    import jax
+    import jax.numpy as jnp
+
+    import repro.simjax.engine as eng
+
+    _, sample_epochs, window_fused = eng._jitted_fused()
+    E, p = len(nets), nets[0].p
+    ranks = list(range(p))
+    n = _bucket(nrep)
+    ch = _chunk_for(p, n)
+    npad = -(-n // ch) * ch
+    starts, seeds, terms = [], [], []
+    for net, sync, op in zip(nets, syncs, ops):
+        starts.append(max(sync.global_time(net, r) for r in ranks) + ws)
+        seeds.append(int(net.rng.integers(2**31)))
+        terms.append(eng._terms(op, p, msize))
+    seeds = np.array(seeds)
+    coeffs = [_coeffs(net, sync, ranks) for net, sync in zip(nets, syncs)]
+    runs = []
+    with eng.x64():
+        durations = None
+        for j in range(len(terms[0])):
+            subs = [terms[e][j][0] for e in range(E)]
+            tp, tm = terms[0][j][1:]
+            t0_op = np.array([s.base_time(tp, tm) * s._bias_for(net)
+                              for s, net in zip(subs, nets)])
+            s0 = subs[0]
+            dur, s_last = sample_epochs(
+                seeds, j, t0_op, np.array([s._ar_state for s in subs]),
+                s0.noise_sigma, s0.autocorr, s0.tail_prob, s0.tail_shift,
+                s0.spike_prob, s0.spike_scale, nrep, n=n)
+            for e, s in enumerate(subs):
+                s._ar_state = float(np.asarray(s_last)[e])
+            durations = dur if durations is None else durations + dur
+        if npad > n:
+            durations = jnp.concatenate(
+                [durations, jnp.broadcast_to(durations[:, n - 1:n],
+                                             (E, npad - n))], axis=1)
+        for e in range(E):
+            key = jax.random.fold_in(jax.random.PRNGKey(int(seeds[e])),
+                                     len(terms[0]))
+            times, errors, et_last = window_fused(
+                durations[e], key, *coeffs[e], ops[e].rank_imbalance,
+                float(starts[e]), ws, nrep, ch=ch)
+            nets[e].t[ranks] = np.asarray(et_last)
+            runs.append((np.asarray(times)[:nrep],
+                         np.asarray(errors)[:nrep]))
+    return runs
+
+
+def _eager_epoch(net, sync, op, msize, nrep, ws):
+    """A per-epoch call as the engine made it with eager device keys;
+    returns the six ``WindowRun`` arrays."""
+    import jax
+
+    import repro.simjax.engine as eng
+
+    _, sample, window = eng._jitted()
+    ranks = list(range(net.p))
+    start = max(sync.global_time(net, r) for r in ranks) + ws
+    n = _bucket(nrep)
+    seed = int(net.rng.integers(2**31))
+    terms = eng._terms(op, net.p, msize)
+    coeffs = _coeffs(net, sync, ranks)
+    with eng.x64():
+        key = jax.random.PRNGKey(seed)
+        durations = None
+        for j, (sub, tp, tm) in enumerate(terms):
+            dur, s = sample(jax.random.fold_in(key, j),
+                            sub.base_time(tp, tm) * sub._bias_for(net),
+                            sub._ar_state, sub.noise_sigma, sub.autocorr,
+                            sub.tail_prob, sub.tail_shift, sub.spike_prob,
+                            sub.spike_scale, n=n)
+            sub._ar_state = float(s[nrep - 1])
+            durations = dur if durations is None else durations + dur
+        out = window(durations, jax.random.fold_in(key, len(terms)),
+                     *coeffs, op.rank_imbalance, start, ws)
+    out = [np.asarray(a)[:nrep] for a in out]
+    net.t[ranks] = out[5][nrep - 1]
+    return out
+
+
+def _ar_states(ops):
+    return [sub._ar_state for op in ops
+            for sub, _, _ in getattr(op, "terms", ((op, 1.0, 1.0),))]
+
+
+@pytest.mark.parametrize("op,nrep", [("single", 8200), ("composite", 300),
+                                     ("repeated", 300)])
+def test_fused_bit_identical_to_eager_keys(op, nrep):
+    """Host keys, the jitted lanes and the single read change no bit of
+    what the fused engine returns or writes back. ``single`` at nrep=8200,
+    p=8 pads the window scan (n=8200 to npad=16384)."""
+    if op == "single":
+        n = _bucket(nrep)
+        ch = _chunk_for(8, n)
+        assert -(-n // ch) * ch > n
+    want_nets, want_syncs, want_ops = _epochs(3, seed0=21,
+                                              op=HOST_PATH_OPS[op])
+    nets, syncs, ops = _epochs(3, seed0=21, op=HOST_PATH_OPS[op])
+    want = _eager_fused(want_nets, want_syncs, want_ops, 4096, nrep, 400e-6)
+    got = run_windowed_epochs_jax(nets, syncs, ops, 4096, nrep, 400e-6)
+    for e in range(3):
+        assert np.array_equal(got[e].times, want[e][0])
+        assert np.array_equal(got[e].errors, want[e][1])
+        assert np.array_equal(nets[e].t, want_nets[e].t)
+    assert _ar_states(ops) == _ar_states(want_ops)
+
+
+@pytest.mark.parametrize("op", list(HOST_PATH_OPS))
+def test_per_epoch_bit_identical_to_eager_keys(op):
+    (want_net,), (want_sync,), (want_op,) = _epochs(1, seed0=23,
+                                                    op=HOST_PATH_OPS[op])
+    (net,), (sync,), (op_,) = _epochs(1, seed0=23, op=HOST_PATH_OPS[op])
+    for _ in range(2):           # the second call starts from the carries
+        want = _eager_epoch(want_net, want_sync, want_op, 4096, 300, 400e-6)
+        run = run_windowed_jax(net, sync, op_, 4096, 300, 400e-6)
+        got = [run.times, run.errors, run.start_global_est,
+               run.end_global_est, run.start_true, run.end_true]
+        for g, w in zip(got, want):
+            assert np.array_equal(g, w)
+        assert np.array_equal(net.t, want_net.t)
+        assert _ar_states([op_]) == _ar_states([want_op])
+
+
+@pytest.mark.parametrize("op,E,order", [
+    ("single", 1, ["sample", "window", "read"]),
+    ("single", 4, ["sample"] + ["window"] * 4 + ["read"]),
+    ("composite", 3, ["sample"] * 2 + ["window"] * 3 + ["read"]),
+    # one object in two terms: its carry is read after each term
+    ("repeated", 3, ["sample", "read"] * 2 + ["window"] * 3 + ["read"]),
+])
+def test_one_host_read_per_fused_call(monkeypatch, op, E, order):
+    """Every window of the call is dispatched before the call's one read;
+    ``sim_host_reads`` counts the reads."""
+    import jax
+
+    import repro.simjax.engine as eng
+
+    nets, syncs, ops = _epochs(E, seed0=29, op=HOST_PATH_OPS[op])
+    seen = []
+    fused = eng._jitted_fused
+
+    def recording():
+        jx, sample, window = fused()
+
+        def rec(name, fn):
+            def call(*a, **kw):
+                seen.append(name)
+                return fn(*a, **kw)
+            return call
+        return jx, rec("sample", sample), rec("window", window)
+
+    get = jax.device_get
+
+    def device_get(tree):
+        seen.append("read")
+        return get(tree)
+
+    monkeypatch.setattr(eng, "_jitted_fused", recording)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    before = telemetry.counters().get("sim_host_reads", 0)
+    run_windowed_epochs_jax(nets, syncs, ops, 4096, 200, 400e-6)
+    assert seen == order
+    assert telemetry.counters()["sim_host_reads"] - before \
+        == order.count("read")
 
 
 # ---------------------------------------------------------------------------

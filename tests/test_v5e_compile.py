@@ -110,3 +110,18 @@ def test_fused_window_program_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert 0 < mem.temp_size_in_bytes < 16 * 2**30
     assert np.isfinite(mem.argument_size_in_bytes)
+
+
+def test_fused_lanes_helper_compiles_for_v5e(one_chip):
+    """The helper between the fused sample and window programs, for a
+    two-term op at a padded size (n=1e5 to npad=100352)."""
+    from repro.simjax.engine import _bucket, _chunk_for, _jitted_lanes, x64
+
+    n = _bucket(_NREP)
+    ch = _chunk_for(_P, n)
+    npad = -(-n // ch) * ch
+    assert npad > n
+    with x64():
+        dur = _spec(one_chip, (_E, n), jnp.float64)
+        compiled = _jitted_lanes().lower((dur, dur), npad=npad).compile()
+    assert len(compiled.out_info) == _E
